@@ -34,7 +34,7 @@ def run() -> None:
     q = jax.random.normal(ks[0], (b, h, s, dh), jnp.bfloat16)
     k = jax.random.normal(ks[1], (b, kh, s, dh), jnp.bfloat16)
     v = jax.random.normal(ks[2], (b, kh, s, dh), jnp.bfloat16)
-    us = _time(lambda *a: flash_attention(*a), q, k, v)
+    us = _time(lambda *a: flash_attention(*a, interpret=True), q, k, v)
     emit("flash_prefill_pallas_interp_b1h8s512", us,
          f"{2 * 2 * b * h * s * s * dh / (us / 1e6) / 1e9:.2f}GFLOP/s-equiv")
     qb = q.transpose(0, 2, 1, 3)
@@ -49,7 +49,8 @@ def run() -> None:
     q1 = jax.random.normal(ks[0], (4, 8, 128), jnp.bfloat16)
     kc = jax.random.normal(ks[1], (4, w, 2, 128), jnp.bfloat16)
     vc = jax.random.normal(ks[2], (4, w, 2, 128), jnp.bfloat16)
-    us = _time(lambda: decode_attention_pallas(q1, kc, vc, w - 1))
+    us = _time(lambda: decode_attention_pallas(q1, kc, vc, w - 1,
+                                                interpret=True))
     emit("flash_decode_pallas_interp_b4w8192", us, "ring-masked")
     us = _time(lambda: decode_attention(q1, kc, vc, w - 1))
     emit("flash_decode_xla_b4w8192", us, "XLA twin")
@@ -60,7 +61,8 @@ def run() -> None:
     v2 = jax.random.normal(ks[2], (1, 8, 1024, 64))
     lw = -jax.nn.sigmoid(jax.random.normal(ks[0], (1, 8, 1024, 64)))
     u = jnp.zeros((8, 64))
-    us = _time(lambda: linear_attention_pallas(q2, k2, v2, lw, u))
+    us = _time(lambda: linear_attention_pallas(q2, k2, v2, lw, u,
+                                                interpret=True))
     emit("rwkv6_chunk_pallas_interp_t1024", us, "chunk=64")
 
 

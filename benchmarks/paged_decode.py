@@ -205,7 +205,8 @@ def run_kernel(*, b: int = 4, h: int = 8, kh: int = 2, bs: int = 16,
     lengths = jnp.asarray([mb * bs - (11 * i) % (mb * bs - 1)
                            for i in range(b)], jnp.int32)
 
-    out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths)
+    out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths,
+                                        interpret=True)
     ref = flash_decode_paged_ref(q.reshape(b, kh, h // kh, dh), k_pool,
                                  v_pool, tables, lengths).reshape(b, h, dh)
     err = float(jnp.max(jnp.abs(out - ref)))
@@ -213,7 +214,7 @@ def run_kernel(*, b: int = 4, h: int = 8, kh: int = 2, bs: int = 16,
     t0 = time.perf_counter()
     for _ in range(iters):
         out = paged_decode_attention_pallas(q, k_pool, v_pool, tables,
-                                            lengths)
+                                            lengths, interpret=True)
     jax.block_until_ready(out)
     us = (time.perf_counter() - t0) / iters * 1e6
     print(f"  [kernel] paged decode parity max|err|={err:.2e}, "

@@ -9,13 +9,12 @@ import argparse
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
 from repro.core.predictor import TrainSettings, train_predictor
 from repro.core.scheduler.policies import fcfs, make_policy, oracle_sjf
 from repro.data.synthetic import make_corpus, sample_lengths
 from repro.data.workload import burst_arrivals, make_requests
 from repro.core.scheduler.scheduler import Scheduler
-from repro.models import transformer as tfm
+from repro.launch.serve import build_model
 from repro.serving import Engine, report
 
 
@@ -32,8 +31,7 @@ def main():
     args = ap.parse_args()
 
     # the served LM (reduced config of the selected family, real weights)
-    cfg = get_smoke_config(args.arch).replace(dtype="float32", vocab_size=2048)
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    cfg, params = build_model(args.arch, smoke=True)
     print(f"serving {cfg.arch_id} (reduced: {cfg.num_layers}L d{cfg.d_model}) "
           f"on {jax.devices()[0].platform}")
 

@@ -11,7 +11,9 @@ token per sequence against a KV cache of up to 512k entries. TPU adaptation:
   layout).
 * Ring-buffer validity/window masking arrives as a precomputed additive f32
   bias vector (0 / -inf per slot), blocked alongside K — no scalar prefetch
-  needed and the same kernel serves append and ring caches.
+  needed and the same kernel serves append and ring caches. It is carried as
+  ``(B, 1, W)`` so each ``(1, block_k)`` tile spans the whole second-minor
+  axis, as the TPU's (8, 128) tiling rule requires of a block.
 
 Memory: per grid step VMEM = block_k·dh (K) + block_k·dh (V) + q_per_kv·dh
 tiles — with defaults (block_k=512, dh=128, bf16) ≈ 256 KiB, far under VMEM.
@@ -19,7 +21,6 @@ tiles — with defaults (block_k=512, dh=128, bf16) ≈ 256 KiB, far under VMEM.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,15 +28,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """``None`` → interpret only off-TPU, so the compiled kernel path is
-    exercised wherever real hardware is present (CI containers are CPU-only
-    and fall back to interpret mode automatically)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
@@ -51,9 +43,9 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
     q = q_ref[0, 0].astype(jnp.float32)                 # (G, dh)
     k = k_ref[0, 0].astype(jnp.float32)                 # (block_k, dh)
     v = v_ref[0, 0].astype(jnp.float32)
-    bias = bias_ref[0].astype(jnp.float32)              # (block_k,)
+    bias = bias_ref[0].astype(jnp.float32)              # (1, block_k)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, bk)
-    s = s + bias[None, :]
+    s = s + bias
 
     m_prev, l_prev = m_scr[...], l_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -71,7 +63,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
 
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  bias: jax.Array, *, block_k: int = 512,
-                 interpret: Optional[bool] = None) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """q: (B, KH, G, dh); caches: (B, KH, W, dh); bias: (B, W) → (B, KH, G, dh).
 
     ``bias`` is 0 for valid slots and ≤ NEG_INF for invalid/out-of-window
@@ -91,7 +83,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pl.BlockSpec((1, 1, g, dh), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, j: (b_, j)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, j: (b_, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dh), lambda b_, h_, j: (b_, h_, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -100,8 +92,8 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, dh), jnp.float32),
         ],
-        interpret=resolve_interpret(interpret),
-    )(q, k_cache, v_cache, bias)
+        interpret=interpret,
+    )(q, k_cache, v_cache, bias.reshape(b, 1, w))
 
 
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -148,7 +140,7 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                        block_tables: jax.Array, lengths: jax.Array, *,
-                       interpret: Optional[bool] = None) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Paged decode attention: K/V gathered through per-sequence block tables.
 
     q: (B, KH, G, dh); pools: (num_blocks, KH, block_size, dh);
@@ -194,6 +186,6 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q, k_pool, v_pool)

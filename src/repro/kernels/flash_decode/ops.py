@@ -7,8 +7,8 @@ drop-in interchangeable behind the model's ``attn_impl`` switch.
 ``paged_decode_attention_pallas`` is the block-table analogue over a global
 ``(num_blocks, KH, block_size, dh)`` KV pool.
 
-``interpret=None`` auto-detects the backend: the compiled kernel runs on
-TPU, interpret mode everywhere else (the CI container is CPU-only).
+Both compile for the TPU by default; pass ``interpret=True`` to run them in
+the Pallas interpreter (how the CPU tests run them).
 """
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ from repro.models.attention import ring_slot_positions
 def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, pos: jax.Array, *,
                             window: Optional[int] = None, block_k: int = 512,
-                            interpret: Optional[bool] = None) -> jax.Array:
+                            interpret: bool = False) -> jax.Array:
     """q: (B, H, dh); caches: (B, W, KH, dh); pos: scalar → (B, H, dh)."""
     b, h, dh = q.shape
     w, kh = k_cache.shape[1], k_cache.shape[2]
@@ -60,8 +60,7 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
 def paged_decode_attention_pallas(q: jax.Array, k_pool: jax.Array,
                                   v_pool: jax.Array, block_tables: jax.Array,
                                   lengths: jax.Array, *,
-                                  interpret: Optional[bool] = None,
-                                  ) -> jax.Array:
+                                  interpret: bool = False) -> jax.Array:
     """q: (B, H, dh); pools: (num_blocks, KH, block_size, dh);
     block_tables: (B, max_blocks) int32; lengths: (B,) int32 → (B, H, dh).
 

@@ -86,7 +86,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, window: Optional[int] = None,
                   block_q: int = 128, block_k: int = 128,
                   true_q: Optional[int] = None, true_k: Optional[int] = None,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     """q: (B, H, Sq, dh); k/v: (B, KH, Skv, dh) → (B, H, Sq, dh).
 
     Sq/Skv must be multiples of the block sizes (ops.py pads).

@@ -25,7 +25,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """(B, H, Sq, dh) × (B, KH, Skv, dh) → (B, H, Sq, dh), padded to blocks."""
     sq, skv = q.shape[2], k.shape[2]
     bq = min(block_q, max(8, 1 << (sq - 1).bit_length()))

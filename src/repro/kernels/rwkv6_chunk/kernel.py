@@ -37,11 +37,19 @@ def _rwkv_kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr, *,
     kc = k_ref[0, 0].astype(jnp.float32)
     vc = v_ref[0, 0].astype(jnp.float32)        # (C, dv)
     lw = lw_ref[0, 0].astype(jnp.float32)       # (C, dk)
-    u = u_ref[0].astype(jnp.float32)            # (dk,)
+    u = u_ref[0].astype(jnp.float32)            # (1, dk)
 
-    inc = jnp.cumsum(lw, axis=0)                # inclusive prefix Σ log w
+    # inclusive prefix Σ log w as a lower-triangular matmul (Mosaic has no
+    # cumsum); the chunk total is needed both as a row and as a column
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    hi = jax.lax.Precision.HIGHEST
+    inc = jnp.dot((row >= col).astype(jnp.float32), lw, precision=hi)
     exc = inc - lw
-    tot = inc[-1:, :]                           # (1, dk)
+    tot = jnp.sum(lw, axis=0, keepdims=True)    # (1, dk)
+    tot_col = jax.lax.dot_general(lw, jnp.ones((chunk, 1), jnp.float32),
+                                  (((0,), (0,)), ((), ())),
+                                  precision=hi)  # (dk, 1)
 
     q_dec = qc * jnp.exp(exc if mode == "rwkv" else inc)
     k_dec = kc * jnp.exp(-inc)
@@ -49,23 +57,25 @@ def _rwkv_kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr, *,
 
     state = state_scr[...]                      # (dk, dv)
     inter = jnp.dot(q_dec, state)
-    tri = jnp.tril(jnp.ones((chunk, chunk), jnp.float32), k=-1)
+    tri = (row > col).astype(jnp.float32)      # strictly causal
     att = jax.lax.dot_general(q_dec, k_dec, (((1,), (1,)), ((), ()))) * tri
-    diag = jnp.sum(qc * u[None, :] * kc, axis=-1, keepdims=True)
+    diag = jnp.sum(qc * u * kc, axis=-1, keepdims=True)
     out = inter + jnp.dot(att, vc) + diag * vc
 
-    state_scr[...] = (state * jnp.exp(tot).T
+    state_scr[...] = (state * jnp.exp(tot_col)
                       + jax.lax.dot_general(k_tail, vc, (((0,), (0,)), ((), ()))))
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def rwkv6_chunk(q: jax.Array, k: jax.Array, v: jax.Array, log_decay: jax.Array,
                 bonus: jax.Array, *, chunk: int = 64, mode: str = "rwkv",
-                interpret: bool = True) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """q/k/lw: (B, H, T, dk); v: (B, H, T, dv); bonus: (H, dk) → (B, H, T, dv).
 
     T must be a multiple of ``chunk`` (ops.py pads). For ``mode="ssd"`` pass
-    ``bonus=ones`` (the diag term is (q·k) with no decay).
+    ``bonus=ones`` (the diag term is (q·k) with no decay). The bonus rides
+    in as ``(H, 1, dk)`` so its ``(1, dk)`` tile spans the whole second-minor
+    axis, as the TPU's (8, 128) tiling rule requires of a block.
     """
     b, h, t, dk = q.shape
     dv = v.shape[-1]
@@ -80,10 +90,10 @@ def rwkv6_chunk(q: jax.Array, k: jax.Array, v: jax.Array, log_decay: jax.Array,
             pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, chunk, dv), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, c: (b_, h_, c, 0)),
-            pl.BlockSpec((1, dk), lambda b_, h_, c: (h_, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b_, h_, c: (h_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, dv), lambda b_, h_, c: (b_, h_, c, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, log_decay, bonus)
+    )(q, k, v, log_decay, bonus.reshape(h, 1, dk))

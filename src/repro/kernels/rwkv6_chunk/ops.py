@@ -11,7 +11,7 @@ from repro.kernels.rwkv6_chunk.kernel import rwkv6_chunk
 
 @functools.partial(jax.jit, static_argnames=("chunk", "mode", "interpret"))
 def linear_attention_pallas(q, k, v, log_decay, bonus=None, *, chunk: int = 64,
-                            mode: str = "rwkv", interpret: bool = True):
+                            mode: str = "rwkv", interpret: bool = False):
     """Drop-in twin of models.linear_attn.chunked_linear_attention (output
     only — state handoff stays in the XLA path). Pads T to the chunk size and
     broadcasts scalar SSD decay across the k-dim."""

@@ -99,9 +99,14 @@ class RealBackend:
                  tokenizer: Optional[HashTokenizer] = None,
                  sampler: SamplerConfig = SamplerConfig(), seed: int = 0,
                  bucketed: bool = True, min_bucket: int = 8,
-                 record_tokens: bool = False, paged: bool = False):
+                 record_tokens: bool = False, paged: bool = False,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
+        # every array this backend owns (params, KV, slot tokens, inputs) is
+        # committed to one device, so N replicas in one process each run on
+        # their own chip
+        self.device = device or jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.max_batch = max_batch
         self.cache_len = cache_len
         self.prompt_len = prompt_len
@@ -111,17 +116,21 @@ class RealBackend:
         self.paged = paged
         self.tok = tokenizer or HashTokenizer(
             vocab_size=min(cfg.vocab_size, 2048), max_len=prompt_len)
-        self._key = jax.random.PRNGKey(seed)
+        self._key = self._put(jax.random.PRNGKey(seed))
         self.core: Optional[ServingCore] = None
 
         # --- slot state ------------------------------------------------------
         self.slot_req: List[Optional[Request]] = [None] * max_batch
         self._slot_of: Dict[int, int] = {}
         self._ids: Dict[int, List[int]] = {}    # req_id -> encoded prompt ids
-        self.slot_tokens = jnp.zeros((max_batch, 1), jnp.int32)
-        row_cache = jax.eval_shape(lambda: tfm.init_cache(cfg, 1, cache_len))
-        self.cache = jax.tree.map(
-            lambda l: jnp.zeros((max_batch,) + l.shape, l.dtype), row_cache)
+        self.slot_tokens = self._put(np.zeros((max_batch, 1), np.int32))
+        # one slot's cache shapes: contiguous mode allocates max_batch lanes
+        # of it; paged mode only reads the K/V shape and dtype for its pool
+        self._row_cache = jax.eval_shape(
+            lambda: tfm.init_cache(cfg, 1, cache_len))
+        self.cache = None if paged else jax.tree.map(
+            lambda l: jnp.zeros((max_batch,) + l.shape, l.dtype,
+                                device=self.device), self._row_cache)
 
         # --- prefix-cache fragment store (contiguous mode only) --------------
         # chunk-chain hash -> {"k": (L, block, kvH, D), "v": ...} device K/V of
@@ -343,16 +352,23 @@ class RealBackend:
         (rows at positions >= pos are masked to an exact constant, so
         gathered-garbage lanes produce bit-identical outputs), and scatter
         only the blocks the step wrote back into the pool."""
-        cfg, sampler_cfg = self.cfg, self._sampler_cfg
-        kshape = self.cache["k"].shape            # (max_batch, L, 1, W, KH, dh)
-        L, _, W, KH, dh = kshape[1:]
+        row = self._row_cache["k"]                # (L, 1, W, KH, dh)
+        L, _, W, KH, dh = row.shape
         bs = alloc.block_size
-        mb = W // bs
         n = alloc.total_blocks
         self._null_block = n
-        self._lane_blocks = mb
-        self.k_pool = jnp.zeros((n + 1, L, bs, KH, dh), self.cache["k"].dtype)
-        self.v_pool = jnp.zeros((n + 1, L, bs, KH, dh), self.cache["v"].dtype)
+        self._lane_blocks = W // bs
+        self.k_pool, self.v_pool = (
+            jnp.zeros((n + 1, L, bs, KH, dh), row.dtype, device=self.device)
+            for _ in range(2))
+        self._paged_programs(bs)
+
+    def _paged_programs(self, bs: int) -> None:
+        """Define the jitted paged programs for block size ``bs``. They need
+        only shapes, so a compile check can lower them without a pool."""
+        cfg, sampler_cfg = self.cfg, self._sampler_cfg
+        L, _, W, KH, dh = self._row_cache["k"].shape
+        mb = W // bs
 
         def lane(pool, table):
             """Gather one table into a contiguous cache lane
@@ -514,7 +530,7 @@ class RealBackend:
         also compiles the continuation program for every (chunk, batch)
         shape. Returns wall seconds."""
         t0 = time.perf_counter()
-        key = jax.random.PRNGKey(0)
+        key = self._put(jax.random.PRNGKey(0))
         sizes, b = [], 1
         while b < _next_pow2(self.max_batch):
             sizes.append(b)
@@ -541,8 +557,9 @@ class RealBackend:
         bs = self.core.allocator.block_size if self.paged else 0
         for bl in sorted(buckets | ext_lens):
             for bsz in sizes:
-                tokens = jnp.zeros((bsz, bl), jnp.int32)
-                slots = jnp.zeros((bsz,), jnp.int32)
+                tokens = self._put(np.zeros((bsz, bl), np.int32))
+                slots = self._put(np.zeros((bsz,), np.int32))
+                no_commit = self._put(np.zeros((bsz,), bool))
                 if bl in buckets:
                     nxt, cache = self._prefill_bucket(self.params, tokens,
                                                       slots, key)
@@ -550,27 +567,34 @@ class RealBackend:
                         # null-block tables: the warm dispatches scribble on
                         # the trash block only
                         nb = -(-bl // bs)
-                        self._place_paged(
+                        out = self._place_paged(
                             self.k_pool, self.v_pool, cache["k"], cache["v"],
                             self.slot_tokens, nxt,
-                            jnp.full((bsz, nb), self._null_block, jnp.int32),
+                            self._put(np.full((bsz, nb), self._null_block,
+                                              np.int32)),
                             slots)
                     else:
-                        self._place(self.cache, cache, self.slot_tokens, nxt,
-                                    slots)
+                        out = self._place(self.cache, cache, self.slot_tokens,
+                                          nxt, slots)
+                    # one full-width program's outputs at a time: a cache and
+                    # a pool copy each take a lane per slot of device memory
+                    jax.block_until_ready(out)
+                    del nxt, cache, out
                 if bl in ext_lens:
                     if self.paged:
-                        self._extend_chunk_paged(
+                        out = self._extend_chunk_paged(
                             self.params, self.k_pool, self.v_pool,
                             self.slot_tokens, tokens, slots,
-                            jnp.full((bsz, self._lane_blocks),
-                                     self._null_block, jnp.int32),
-                            jnp.zeros((bsz,), jnp.int32),
-                            jnp.zeros((bsz,), bool), key)
+                            self._put(np.full((bsz, self._lane_blocks),
+                                              self._null_block, np.int32)),
+                            self._put(np.zeros((bsz,), np.int32)),
+                            no_commit, key)
                     else:
-                        self._extend_chunk(self.params, self.cache,
-                                           self.slot_tokens, tokens, slots,
-                                           jnp.zeros((bsz,), bool), key)
+                        out = self._extend_chunk(
+                            self.params, self.cache, self.slot_tokens, tokens,
+                            slots, no_commit, key)
+                    jax.block_until_ready(out)
+                    del out
         if self.core is not None and self.core.prefix_caching and \
                 not self.paged:
             # warm the prefix-install ops (fragment concat + lane scatters)
@@ -587,18 +611,22 @@ class RealBackend:
                 self.cache["pos"] = self.cache["pos"].at[0].set(0)
         for bsz in sizes:
             if self.paged:
-                out, _, _ = self._decode_paged(
+                out = self._decode_paged(
                     self.params, self.k_pool, self.v_pool, self.slot_tokens,
-                    jnp.zeros((bsz,), jnp.int32),
-                    jnp.full((bsz, self._lane_blocks), self._null_block,
-                             jnp.int32),
-                    jnp.zeros((bsz,), jnp.int32), key)
+                    self._put(np.zeros((bsz,), np.int32)),
+                    self._put(np.full((bsz, self._lane_blocks),
+                                      self._null_block, np.int32)),
+                    self._put(np.zeros((bsz,), np.int32)), key)
             else:
-                out, _ = self._decode_active(self.params, self.cache,
-                                             self.slot_tokens,
-                                             jnp.zeros((bsz,), jnp.int32), key)
-        jax.block_until_ready(out)
+                out = self._decode_active(
+                    self.params, self.cache, self.slot_tokens,
+                    self._put(np.zeros((bsz,), np.int32)), key)
+            jax.block_until_ready(out)
         return time.perf_counter() - t0
+
+    def _put(self, x):
+        """Commit a host array (or a PRNG key) to this backend's device."""
+        return jax.device_put(x, self.device)
 
     def _now(self, fallback: float) -> float:
         return self.core.clock.now() if self.core is not None else fallback
@@ -718,9 +746,9 @@ class RealBackend:
             tokens[len(group):] = tokens[0]     # padding lanes replay lane 0
             slots[len(group):] = slots[0]
             self._key, sub = jax.random.split(self._key)
-            slots_j = jnp.asarray(slots)
+            slots_j = self._put(slots)
             nxt, bucket_cache = self._prefill_bucket(
-                self.params, jnp.asarray(tokens), slots_j, sub)
+                self.params, self._put(tokens), slots_j, sub)
             if self.paged:
                 bs = self.core.allocator.block_size
                 nb = -(-bucket_len // bs)
@@ -731,10 +759,13 @@ class RealBackend:
                 self.k_pool, self.v_pool, self.slot_tokens = self._place_paged(
                     self.k_pool, self.v_pool, bucket_cache["k"],
                     bucket_cache["v"], self.slot_tokens, nxt,
-                    jnp.asarray(tables), slots_j)
+                    self._put(tables), slots_j)
             else:
                 self.cache, self.slot_tokens = self._place(
                     self.cache, bucket_cache, self.slot_tokens, nxt, slots_j)
+            # the bucket's cache is a lane per slot: free it before the
+            # next program allocates
+            del nxt, bucket_cache
             self.prefill_dispatches += 1
 
         for chunk_len, group in sorted(ext_groups.items()):
@@ -763,14 +794,14 @@ class RealBackend:
                 self.slot_tokens, self.k_pool, self.v_pool = \
                     self._extend_chunk_paged(
                         self.params, self.k_pool, self.v_pool,
-                        self.slot_tokens, jnp.asarray(tokens),
-                        jnp.asarray(slots), jnp.asarray(tables),
-                        jnp.asarray(starts), jnp.asarray(commit), sub)
+                        self.slot_tokens, self._put(tokens),
+                        self._put(slots), self._put(tables),
+                        self._put(starts), self._put(commit), sub)
             else:
                 self.slot_tokens, self.cache = self._extend_chunk(
                     self.params, self.cache, self.slot_tokens,
-                    jnp.asarray(tokens), jnp.asarray(slots),
-                    jnp.asarray(commit), sub)
+                    self._put(tokens), self._put(slots),
+                    self._put(commit), sub)
             self.extend_dispatches += 1
 
         jax.block_until_ready(self.slot_tokens)
@@ -818,10 +849,10 @@ class RealBackend:
                 poss[j] = self._pos[req.req_id]
             self.slot_tokens, self.k_pool, self.v_pool = self._decode_paged(
                 self.params, self.k_pool, self.v_pool, self.slot_tokens,
-                jnp.asarray(idx), jnp.asarray(tables), jnp.asarray(poss), sub)
+                self._put(idx), self._put(tables), self._put(poss), sub)
         else:
             self.slot_tokens, self.cache = self._decode_active(
-                self.params, self.cache, self.slot_tokens, jnp.asarray(idx),
+                self.params, self.cache, self.slot_tokens, self._put(idx),
                 sub)
         jax.block_until_ready(self.slot_tokens)
         now = self._now(now)
@@ -853,6 +884,7 @@ class Engine:
                  bucketed: bool = True,
                  paged: Optional[bool] = None,
                  record_tokens: bool = False,
+                 device: Optional[jax.Device] = None,
                  config: Optional[ServingConfig] = None,
                  **core_kw):
         if paged is None:
@@ -871,7 +903,7 @@ class Engine:
             cfg, params, max_batch=s, cache_len=cache_len,
             prompt_len=prompt_len, tokenizer=tokenizer, sampler=sampler,
             seed=seed, bucketed=bucketed, record_tokens=record_tokens,
-            paged=paged)
+            paged=paged, device=device)
         self.allocator = allocator or BlockAllocator(
             total_blocks=s * (-(-cache_len // 16)), block_size=16)
         self.core = ServingCore(scheduler, self.backend,

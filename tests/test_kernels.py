@@ -33,7 +33,7 @@ def test_flash_prefill_matches_ref(b, h, kh, sq, skv, dh, dtype):
     q = jax.random.normal(ks[0], (b, h, sq, dh), dtype)
     k = jax.random.normal(ks[1], (b, kh, skv, dh), dtype)
     v = jax.random.normal(ks[2], (b, kh, skv, dh), dtype)
-    out = flash_attention(q, k, v)
+    out = flash_attention(q, k, v, interpret=True)
     ref = flash_prefill_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -44,7 +44,7 @@ def test_flash_prefill_sliding_window():
     q = jax.random.normal(ks[0], (1, 4, 256, 64))
     k = jax.random.normal(ks[1], (1, 2, 256, 64))
     v = jax.random.normal(ks[2], (1, 2, 256, 64))
-    out = flash_attention(q, k, v, window=64)
+    out = flash_attention(q, k, v, window=64, interpret=True)
     ref = flash_prefill_ref(q, k, v, window=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -61,7 +61,8 @@ def test_flash_decode_matches_model(b, h, kh, w, dh, pos, win, dtype):
     q = jax.random.normal(ks[0], (b, h, dh), dtype)
     kc = jax.random.normal(ks[1], (b, w, kh, dh), dtype)
     vc = jax.random.normal(ks[2], (b, w, kh, dh), dtype)
-    out = decode_attention_pallas(q, kc, vc, pos, window=win)
+    out = decode_attention_pallas(q, kc, vc, pos, window=win,
+                                  interpret=True)
     ref = decode_attention(q, kc, vc, pos, window=win)  # XLA twin in the model
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -91,7 +92,8 @@ def test_flash_decode_paged_matches_ref(b, h, kh, bs, mb, dh, dtype):
     # ragged: one full sequence, the rest at assorted partial lengths
     lengths = jnp.asarray([mb * bs - (i * 7) % (mb * bs - 1) if i else mb * bs
                            for i in range(b)], jnp.int32)
-    out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths)
+    out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths,
+                                        interpret=True)
     g = h // kh
     ref = flash_decode_paged_ref(q.reshape(b, kh, g, dh), k_pool, v_pool,
                                  tables, lengths).reshape(b, h, dh)
@@ -111,7 +113,8 @@ def test_flash_decode_paged_linear_table_matches_contiguous(dtype):
     q = jax.random.normal(ks[2], (b, h, dh), dtype)
     tables = jnp.arange(b * mb, dtype=jnp.int32).reshape(b, mb)
     lengths = jnp.asarray([mb * bs, mb * bs - 37], jnp.int32)
-    paged = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths)
+    paged = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths,
+                                          interpret=True)
     # contiguous layout: (b, w, kh, dh) cache holding the same rows
     kc = jnp.moveaxis(k_pool.reshape(b, mb, kh, bs, dh), 2, 1) \
         .reshape(b, kh, mb * bs, dh)
@@ -122,7 +125,8 @@ def test_flash_decode_paged_linear_table_matches_contiguous(dtype):
         # count entries — pos = length - 1 views the same rows
         row = decode_attention_pallas(
             q[i:i + 1], jnp.moveaxis(kc[i:i + 1], 1, 2),
-            jnp.moveaxis(vc[i:i + 1], 1, 2), int(lengths[i]) - 1)
+            jnp.moveaxis(vc[i:i + 1], 1, 2), int(lengths[i]) - 1,
+            interpret=True)
         np.testing.assert_allclose(np.asarray(paged[i], np.float32),
                                    np.asarray(row[0], np.float32),
                                    **_tol(dtype))
@@ -141,7 +145,8 @@ def test_flash_decode_paged_aliased_tables_share_exactly():
     shared = [3, 9, 5]
     tables = jnp.asarray([shared + [11], shared + [20]], jnp.int32)
     lengths = jnp.asarray([3 * bs, 3 * bs], jnp.int32)  # tail block masked
-    out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths)
+    out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths,
+                                        interpret=True)
     assert np.array_equal(np.asarray(out[0]), np.asarray(out[1]))
 
 
@@ -161,7 +166,7 @@ def test_rwkv6_chunk_vs_recurrent(mode, b, h, t, dk, dv):
     u = (jax.random.normal(ks[4], (h, dk)) * 0.1 if mode == "rwkv"
          else jnp.ones((h, dk)))
     out = linear_attention_pallas(q, k, v, lw, u if mode == "rwkv" else None,
-                                  mode=mode)
+                                  mode=mode, interpret=True)
     ref = rwkv6_recurrent_ref(q, k, v, lw, u, mode=mode)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-4, rtol=5e-4)

@@ -159,3 +159,31 @@ def test_paged_auto_default_skips_recurrent_families():
     with pytest.raises(ValueError, match="attention-family"):
         Engine(cfg, params, Scheduler(policy=fcfs(), max_batch=2),
                cache_len=64, prompt_len=16, paged=True)
+
+
+def test_paged_mode_holds_only_the_pool(setup):
+    """Paged mode keeps no contiguous per-slot cache: the pool (plus its
+    null block) is the only KV it allocates; contiguous mode keeps lanes."""
+    cfg, params = setup
+    sched = lambda: Scheduler(policy=fcfs(), max_batch=2)  # noqa: E731
+    paged = Engine(cfg, params, sched(), cache_len=64, prompt_len=16)
+    assert paged.backend.paged and paged.backend.cache is None
+    assert paged.backend.k_pool.shape[0] == paged.allocator.total_blocks + 1
+    lanes = Engine(cfg, params, sched(), cache_len=64, prompt_len=16,
+                   paged=False)
+    assert lanes.backend.cache["k"].shape[:1] == (2,)
+
+
+def test_backend_commits_its_arrays_to_its_device(setup):
+    """A backend given a device keeps its params, KV pool and slot tokens
+    there and serves from it — what lets replicas share one process."""
+    cfg, params = setup
+    dev = jax.devices()[-1]
+    eng = Engine(cfg, params, Scheduler(policy=fcfs(), max_batch=2),
+                 cache_len=64, prompt_len=16, device=dev, record_tokens=True)
+    be = eng.backend
+    eng.submit([Request(i, _words(8, f"d{i}"), 0.0, 8, 3) for i in range(2)])
+    assert len(eng.run()) == 2
+    for a in (be.k_pool, be.v_pool, be.slot_tokens,
+              *jax.tree.leaves(be.params)):
+        assert a.devices() == {dev}
