@@ -7,7 +7,7 @@ per-request prefill (the pre-refactor behaviour) vs batched bucketed prefill
 Both modes pre-compile their shape grid (``Engine.warmup``, the vLLM-style
 startup warmup), then serve full-batch bursts so every rep is one admission
 cycle. Reported per mode: warmup seconds, prefill dispatches, and wall
-seconds spent in admission.
+seconds spent in admission (the ``engine.prefill`` span, traced).
 """
 from __future__ import annotations
 
@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import emit
+from repro import tracing
 from repro.configs import get_smoke_config
 from repro.core.scheduler.policies import fcfs
 from repro.core.scheduler.request import Request
@@ -44,17 +45,18 @@ def run(batch: int = 8, reps: int = 4, arch: str = "llama3_2_3b") -> dict:
         eng = Engine(cfg, params, sched, cache_len=64, prompt_len=32,
                      bucketed=bucketed)
         warm_s = eng.warmup()
-        for rep in range(reps):           # full-batch burst = 1 admission cycle
-            eng.submit(_burst(batch, seed=rep))
-            eng.run()
-            assert len(eng.finished) == batch * (rep + 1)
+        with tracing.recording():
+            for rep in range(reps):       # full-batch burst = 1 admission cycle
+                eng.submit(_burst(batch, seed=rep))
+                eng.run()
+                assert len(eng.finished) == batch * (rep + 1)
+        prefill_s = tracing.snapshot()["engine.prefill"]["s"]
         results[mode] = dict(dispatches=eng.backend.prefill_dispatches,
-                             prefill_s=eng.backend.prefill_seconds,
-                             warmup_s=warm_s)
+                             prefill_s=prefill_s, warmup_s=warm_s)
         print(f"{mode:10s} warmup={warm_s:6.1f} s "
               f"dispatches={eng.backend.prefill_dispatches:3d} "
               f"(over {reps} bursts of {batch})  "
-              f"admission={eng.backend.prefill_seconds * 1e3:8.1f} ms")
+              f"admission={prefill_s * 1e3:8.1f} ms")
     seq, buk = results["sequential"], results["bucketed"]
     speedup = seq["prefill_s"] / max(buk["prefill_s"], 1e-9)
     print(f"bucketed admission: {seq['dispatches']}→{buk['dispatches']} "

@@ -21,6 +21,7 @@ from repro.core.predictor.losses import (l1_pointwise_loss, listmle_loss,
 from repro.core.predictor.metrics import kendall_tau_b
 from repro.core.predictor.pairing import build_pairs
 from repro.core.predictor.tokenizer import HashTokenizer
+from repro.tracing import span
 from repro.training.optimizer import Adam, apply_updates
 
 PyTree = Any
@@ -61,7 +62,10 @@ class RankingPredictor:
         return np.asarray(self._jit_fwd(self.params, tokens=jnp.asarray(tokens)))
 
     def score(self, prompts) -> np.ndarray:
-        return self.score_tokens(self.tokenizer.encode_batch(list(prompts)))
+        with span("pred.tokenize"):
+            tokens = self.tokenizer.encode_batch(list(prompts))
+        with span("pred.forward"):
+            return self.score_tokens(tokens)
 
     # ------------------------------------------------------------ persistence
     def save(self, path: str) -> None:

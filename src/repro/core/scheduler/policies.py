@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
 
 from repro.core.scheduler.request import Request
+from repro.tracing import span
 
 POLICY_NAMES = ("fcfs", "pars", "pars+", "pointwise", "listwise", "oracle")
 
@@ -104,7 +105,8 @@ class Policy:
                 return None             # not this opportunity: stay degraded
         t0 = time.perf_counter() if self.scorer_timeout_s is not None else 0.0
         try:
-            scores = self.scorer(prompts)
+            with span("pred.score"):
+                scores = self.scorer(prompts)
         except Exception as e:          # noqa: BLE001 — any scorer fault
             return self._note_failure(repr(e))
         if (self.scorer_timeout_s is not None

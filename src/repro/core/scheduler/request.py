@@ -55,6 +55,9 @@ class Request:
     state: RequestState = RequestState.WAITING
     # runtime bookkeeping
     start_time: Optional[float] = None        # admitted to running queue
+    # first move from waiting to running; re-admission after a preemption
+    # overwrites ``start_time`` but leaves this alone
+    first_admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     tokens_done: int = 0

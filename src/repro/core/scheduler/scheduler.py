@@ -35,6 +35,7 @@ from typing import Callable, List, Optional, Tuple
 
 from repro.core.scheduler.policies import Policy
 from repro.core.scheduler.request import Request, RequestState
+from repro.tracing import span
 
 DEFAULT_STARVATION_S = 120.0
 
@@ -133,6 +134,10 @@ class Scheduler:
         and the admission scan both reuse that one sort (victims evicted
         mid-cycle are inserted in rank order, not re-sorted).
         """
+        with span("sched.schedule"):
+            return self._schedule(now)
+
+    def _schedule(self, now: float) -> List[Request]:
         self.retire_finished(now)
         if not self.continuous and self.running:
             return []
@@ -167,6 +172,8 @@ class Scheduler:
         for r in admitted:
             r.state = RequestState.RUNNING
             r.start_time = now
+            if r.first_admit_time is None:
+                r.first_admit_time = now
         self.running.extend(admitted)
         return admitted
 

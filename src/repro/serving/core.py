@@ -96,6 +96,7 @@ from repro.serving.config import ServingConfig
 from repro.serving.faults import ReplicaCrashed
 from repro.serving.kv_cache import (UNBOUNDED_BLOCKS, BlockAllocator,
                                     prefix_chunk_hashes)
+from repro.tracing import span
 
 # One planned unit of prefill work: (request, start, end) in the backend's
 # prompt-token space — prefill prompt tokens [start, end) of this request.
@@ -829,7 +830,8 @@ class ServingCore:
             elif now - self._last_rerank_t >= self.rerank_interval:
                 due = True
         if due:
-            self.scheduler.rerank(now, floor=self.rerank_floor)
+            with span("sched.rerank"):
+                self.scheduler.rerank(now, floor=self.rerank_floor)
             self._steps_since_rerank = 0
             self._last_rerank_t = now
 
@@ -843,6 +845,10 @@ class ServingCore:
         → prefill ≤ chunk tokens → one decode token for every fully
         prefilled running request → retire. Every fault-tolerance stage is
         a no-op (a flag test) unless its feature was configured."""
+        with span("serve.step"):
+            return self._step(now)
+
+    def _step(self, now: float) -> float:
         self.step_count += 1
         if self.fault_hook is not None:
             self.fault_hook(self, now)
@@ -902,9 +908,11 @@ class ServingCore:
         while self._pending and self._pending[0].arrival_time <= now:
             arrived.append(self._pending.popleft())
         if arrived:
-            self.scheduler.add_requests(arrived)
+            with span("serve.deliver"):
+                self.scheduler.add_requests(arrived)
         if not self.scheduler.has_work:
-            self.clock.wait_until(self._pending[0].arrival_time)
+            with span("serve.wait"):
+                self.clock.wait_until(self._pending[0].arrival_time)
             return self.clock.now()
         running_before = bool(self.scheduler.running)
         finished_before = len(self.finished)
@@ -919,7 +927,8 @@ class ServingCore:
         if not progressed:
             # KV gate rejected everything and nothing is executing
             if self._pending:
-                self.clock.wait_until(self._pending[0].arrival_time)
+                with span("serve.wait"):
+                    self.clock.wait_until(self._pending[0].arrival_time)
                 return self.clock.now()
 
             # effective demand: blocks a request must newly claim, after

@@ -85,6 +85,7 @@ from repro.serving.kv_cache import (UNBOUNDED_BLOCKS, BlockAllocator,
                                     prefix_chunk_hashes)
 from repro.serving.metrics import LatencyReport, RunCounters, report
 from repro.serving.sampler import SamplerConfig, sample
+from repro.tracing import span
 
 
 def _next_pow2(n: int) -> int:
@@ -158,9 +159,12 @@ class RealBackend:
         self.prefill_dispatches = 0   # jitted first-chunk forward_seq launches
         self.extend_dispatches = 0    # jitted continuation-chunk launches
         self.prefill_requests = 0     # requests whose prefill completed
-        self.prefill_seconds = 0.0    # wall time spent in admission/prefill
         self.prefix_installs = 0      # lanes seeded from the fragment store
         self.prefix_tokens_copied = 0  # KV tokens installed instead of computed
+        self.prefill_tokens = 0       # positions dispatched to prefill, lanes
+        self.prefill_pad_tokens = 0   # × length; of them bucket or lane pads
+        self.decode_rows = 0          # rows dispatched to the decode program
+        self.decode_rows_padded = 0   # of them power-of-two padding
 
         # --- jitted programs -------------------------------------------------
         sampler_cfg = sampler
@@ -187,12 +191,14 @@ class RealBackend:
 
             Returns ``(next_token (B,), cache)`` where cache leaves are
             (L, B, cache_len, ...) plus the ``pos`` scalar."""
-            logits, cache, _ = tfm.forward_seq(
-                params, cfg, tokens, build_cache=True, cache_len=cache_len,
-                remat="none")
+            with jax.named_scope("model"):
+                logits, cache, _ = tfm.forward_seq(
+                    params, cfg, tokens, build_cache=True,
+                    cache_len=cache_len, remat="none")
             keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(slot_ids)
-            nxt = jax.vmap(lambda lg, k: sample(lg, k, sampler_cfg))(
-                logits[:, -1], keys)
+            with jax.named_scope("sample"):
+                nxt = jax.vmap(lambda lg, k: sample(lg, k, sampler_cfg))(
+                    logits[:, -1], keys)
             return nxt, cache
 
         @jax.jit
@@ -392,8 +398,9 @@ class RealBackend:
                 xb = x[:, :, :nb * bs].reshape(L, x.shape[1], nb, bs, KH, dh)
                 return jnp.moveaxis(xb, (0, 1, 2), (2, 0, 1))
 
-            k_pool = k_pool.at[tables].set(to_blocks(bucket_k))
-            v_pool = v_pool.at[tables].set(to_blocks(bucket_v))
+            with jax.named_scope("pool_write"):
+                k_pool = k_pool.at[tables].set(to_blocks(bucket_k))
+                v_pool = v_pool.at[tables].set(to_blocks(bucket_v))
             return k_pool, v_pool, full_tokens.at[slot_ids].set(nxt[:, None])
 
         @jax.jit
@@ -411,17 +418,21 @@ class RealBackend:
             keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(slot_ids)
 
             def one(table, toks, start, k):
-                row = {"k": lane(k_pool, table), "v": lane(v_pool, table),
-                       "pos": start}
-                logits, new_row = tfm.forward_chunk(params, cfg,
-                                                    toks[None], row)
+                with jax.named_scope("lane_gather"):
+                    row = {"k": lane(k_pool, table),
+                           "v": lane(v_pool, table), "pos": start}
+                with jax.named_scope("model"):
+                    logits, new_row = tfm.forward_chunk(params, cfg,
+                                                        toks[None], row)
                 jc = jnp.clip(start // bs, 0, mb - nb_w)
                 dest = jax.lax.dynamic_slice(table, (jc,), (nb_w,))
                 dk = jax.lax.dynamic_slice(new_row["k"], (0, 0, jc * bs, 0, 0),
                                            (L, 1, nb_w * bs, KH, dh))
                 dv = jax.lax.dynamic_slice(new_row["v"], (0, 0, jc * bs, 0, 0),
                                            (L, 1, nb_w * bs, KH, dh))
-                return sample(logits[0, -1], k, sampler_cfg), dest, dk, dv
+                with jax.named_scope("sample"):
+                    nxt = sample(logits[0, -1], k, sampler_cfg)
+                return nxt, dest, dk, dv
 
             nxt, dest, dk, dv = jax.vmap(one)(tables, tokens, starts, keys)
 
@@ -429,8 +440,9 @@ class RealBackend:
                 xb = x[:, :, 0].reshape(x.shape[0], L, nb_w, bs, KH, dh)
                 return jnp.moveaxis(xb, 2, 1)
 
-            k_pool = k_pool.at[dest].set(to_blocks(dk))
-            v_pool = v_pool.at[dest].set(to_blocks(dv))
+            with jax.named_scope("pool_write"):
+                k_pool = k_pool.at[dest].set(to_blocks(dk))
+                v_pool = v_pool.at[dest].set(to_blocks(dv))
             kept = jnp.where(commit[:, None], nxt[:, None],
                              full_tokens[slot_ids])
             return full_tokens.at[slot_ids].set(kept), k_pool, v_pool
@@ -448,21 +460,26 @@ class RealBackend:
             keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(idx)
 
             def one(table, token_row, pos, k):
-                row = {"k": lane(k_pool, table), "v": lane(v_pool, table),
-                       "pos": pos}
-                logits, new_row = tfm.decode_step(params, cfg, row,
-                                                  token_row[None])
+                with jax.named_scope("lane_gather"):
+                    row = {"k": lane(k_pool, table),
+                           "v": lane(v_pool, table), "pos": pos}
+                with jax.named_scope("model"):
+                    logits, new_row = tfm.decode_step(params, cfg, row,
+                                                      token_row[None])
                 j = (pos % W) // bs
                 dest = table[j]
                 dk = jax.lax.dynamic_slice(new_row["k"], (0, 0, j * bs, 0, 0),
                                            (L, 1, bs, KH, dh))
                 dv = jax.lax.dynamic_slice(new_row["v"], (0, 0, j * bs, 0, 0),
                                            (L, 1, bs, KH, dh))
-                return sample(logits[0], k, sampler_cfg), dest, dk, dv
+                with jax.named_scope("sample"):
+                    nxt = sample(logits[0], k, sampler_cfg)
+                return nxt, dest, dk, dv
 
             nxt, dest, dk, dv = jax.vmap(one)(tables, sub_tokens, poss, keys)
-            k_pool = k_pool.at[dest].set(dk[:, :, 0])
-            v_pool = v_pool.at[dest].set(dv[:, :, 0])
+            with jax.named_scope("pool_write"):
+                k_pool = k_pool.at[dest].set(dk[:, :, 0])
+                v_pool = v_pool.at[dest].set(dv[:, :, 0])
             return full_tokens.at[idx].set(nxt[:, None]), k_pool, v_pool
 
         self._place_paged = _place_paged
@@ -680,6 +697,12 @@ class RealBackend:
         self.prefix_installs += 1
         self.prefix_tokens_copied += n_tokens
 
+    def _count_prefill(self, positions: int, real: int) -> None:
+        """One prefill dispatch of ``positions`` token positions (lanes ×
+        length), ``real`` of them prompt tokens: the rest is padding."""
+        self.prefill_tokens += positions
+        self.prefill_pad_tokens += positions - real
+
     def prefill(self, chunks: Sequence[PrefillChunk], now: float) -> float:
         """Execute one step's planned prefill chunks (see ``ServingCore``).
 
@@ -696,9 +719,12 @@ class RealBackend:
         (tokens_done/TTFT bookkeeping preserved across preemption
         re-admission, matching SimBackend's recompute semantics) and its
         prefix blocks' KV copied into the fragment store."""
+        with span("engine.prefill"):
+            return self._prefill(chunks, now)
+
+    def _prefill(self, chunks: Sequence[PrefillChunk], now: float) -> float:
         if not chunks:
             return now
-        t0 = time.perf_counter()
         first_groups: Dict[int, list] = {}
         ext_groups: Dict[int, list] = {}
         for req, start, end in chunks:
@@ -745,6 +771,8 @@ class RealBackend:
                 slots[j] = self._slot_of[req.req_id]
             tokens[len(group):] = tokens[0]     # padding lanes replay lane 0
             slots[len(group):] = slots[0]
+            self._count_prefill(tokens.size, sum(
+                min(len(self._prompt_ids(r)), bucket_len) for r in group))
             self._key, sub = jax.random.split(self._key)
             slots_j = self._put(slots)
             nxt, bucket_cache = self._prefill_bucket(
@@ -783,6 +811,8 @@ class RealBackend:
             tokens[len(group):] = tokens[0]
             slots[len(group):] = slots[0]
             starts[len(group):] = starts[0]
+            self._count_prefill(tokens.size, sum(
+                len(self._prompt_ids(r)[s:e]) for r, s, e in group))
             commit[len(group):] = commit[0]
             self._key, sub = jax.random.split(self._key)
             if self.paged:
@@ -804,8 +834,8 @@ class RealBackend:
                     self._put(commit), sub)
             self.extend_dispatches += 1
 
-        jax.block_until_ready(self.slot_tokens)
-        self.prefill_seconds += time.perf_counter() - t0
+        with span("engine.prefill.sync"):
+            jax.block_until_ready(self.slot_tokens)
         now = self._now(now)
         toks = self._tokens_snapshot()
         for req, _start, end in chunks:
@@ -829,6 +859,12 @@ class RealBackend:
         return now
 
     def decode(self, now: float) -> float:
+        """One decode token for every decode-ready slot, in one dispatch of
+        the active rows padded to a power of two."""
+        with span("engine.decode"):
+            return self._decode(now)
+
+    def _decode(self, now: float) -> float:
         ready = (self.core.decode_ready if self.core is not None
                  else lambda r: True)
         active = [i for i, r in enumerate(self.slot_req)
@@ -838,6 +874,8 @@ class RealBackend:
         idx = np.asarray(
             active + [active[0]] * (_next_pow2(len(active)) - len(active)),
             np.int32)
+        self.decode_rows += len(idx)
+        self.decode_rows_padded += len(idx) - len(active)
         self._key, sub = jax.random.split(self._key)
         if self.paged:
             tables = np.full((len(idx), self._lane_blocks), self._null_block,
@@ -854,7 +892,8 @@ class RealBackend:
             self.slot_tokens, self.cache = self._decode_active(
                 self.params, self.cache, self.slot_tokens, self._put(idx),
                 sub)
-        jax.block_until_ready(self.slot_tokens)
+        with span("engine.decode.sync"):
+            jax.block_until_ready(self.slot_tokens)
         now = self._now(now)
         toks = self._tokens_snapshot()
         for i in active:
