@@ -489,6 +489,38 @@ def decode_step(params, cfg: ModelConfig, cache: PyTree, token: jax.Array,
     return logits, new_cache
 
 
+def decode_step_paged(params, cfg: ModelConfig, k_pool: jax.Array,
+                      v_pool: jax.Array, table: jax.Array, pos, token: jax.Array,
+                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decode step of one sequence whose KV lives in block pools.
+
+    ``k_pool``/``v_pool``: (N, L, bs, KH, dh), read only; ``table``:
+    (W // bs,) the blocks of the sequence's W-row lane; ``pos``: scalar;
+    ``token``: (1,) int32. Each layer gathers its own lane straight from the
+    pool (``pool[table, l]``) and runs ``layer_step`` on it, so the math is
+    ``decode_step``'s over the same values; only the row written at
+    ``pos % W`` leaves the layer. Returns (logits (V,), k_rows (L, KH, dh),
+    v_rows (L, KH, dh)); the caller writes the rows into the pools."""
+    _, L, bs, kh, dh = k_pool.shape
+    w = table.shape[0] * bs
+    slot = jnp.mod(jnp.asarray(pos, jnp.int32), w)
+    x = _embed(params, cfg, token[None], start_pos=pos)
+
+    def body(x, xs):
+        lp, l = xs
+        with jax.named_scope("lane_gather"):
+            lane = {"k": k_pool[table, l].reshape(1, w, kh, dh),
+                    "v": v_pool[table, l].reshape(1, w, kh, dh)}
+        x, new_lane, _ = layer_step(lp, x, cfg, lane, pos)
+        return x, (new_lane["k"][0, slot], new_lane["v"][0, slot])
+
+    x, (k_rows, v_rows) = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    x = apply_norm(params["final_norm"], x, cfg.norm)
+    logits = _unembed(params, cfg, x)[0, 0]
+    return logits, k_rows, v_rows
+
+
 # ===========================================================================
 # Cache construction (decode entry without prefill — dry-run / fresh session)
 # ===========================================================================

@@ -34,11 +34,13 @@ attention-family, non-enc-dec, non-sliding-window models): KV lives in one
 global block pool ``(total_blocks + 1, L, block_size, KH, dh)`` per K and V
 instead of per-slot lanes, indexed by each request's allocator block table
 (plus a trailing *null* block that absorbs padded-table writes and whose
-reads are always masked). Dispatches gather a request's table into a
-contiguous lane, run the same forward/decode math as contiguous mode — rows
-past the lane ``pos`` are masked to an exact constant, so outputs are
+reads are always masked). Continuation chunks gather a request's table
+into a contiguous lane; decode gathers each layer's lane from the pool inside
+the layer loop. Both run the same forward/decode math as contiguous mode —
+rows past the lane ``pos`` are masked to an exact constant, so outputs are
 bit-identical, not approximately equal (``tests/test_paged_decode.py``) —
-and scatter back only the blocks the step wrote. See docs/architecture.md
+and write back only what the step wrote: the touched blocks of a chunk, one
+row per layer of a decode. See docs/architecture.md
 §"Paged KV" for the table lifecycle and the incremental
 (``kv_reservation="incremental"``) grow-or-preempt contract.
 
@@ -352,12 +354,13 @@ class RealBackend:
 
         Layout: ``(total_blocks + 1, L, block_size, KH, dh)`` per pool —
         block-major so one table entry is one contiguous row. The serving
-        truth lives here; per-dispatch the programs gather a request's
-        table into a contiguous ``(L, 1, cache_len, KH, dh)`` lane, run the
-        *same* ``forward_chunk`` / ``decode_step`` math as contiguous mode
-        (rows at positions >= pos are masked to an exact constant, so
-        gathered-garbage lanes produce bit-identical outputs), and scatter
-        only the blocks the step wrote back into the pool."""
+        truth lives here; per dispatch a continuation chunk gathers a
+        request's table into a contiguous ``(L, 1, cache_len, KH, dh)``
+        lane and decode gathers one ``(1, cache_len, KH, dh)`` lane per
+        layer; both run the *same* ``forward_chunk`` / ``layer_step`` math
+        as contiguous mode (rows at positions >= pos are masked to an exact
+        constant, so gathered-garbage lanes produce bit-identical outputs),
+        and write only what the step wrote back into the pool."""
         row = self._row_cache["k"]                # (L, 1, W, KH, dh)
         L, _, W, KH, dh = row.shape
         bs = alloc.block_size
@@ -450,36 +453,33 @@ class RealBackend:
         @jax.jit
         def _decode_paged(params, k_pool, v_pool, full_tokens, idx, tables,
                           poss, key):
-            """One decode iteration over gathered lanes (paged twin of
-            ``_decode_active``). ``poss`` is the host-tracked lane position
-            per active slot; the single KV row the step writes lands in
-            block ``table[(pos % W) // bs]`` — a wrap (pos >= W, caching
-            off) overwrites the sequence's own oldest block, which is
-            exactly the contiguous ring semantics."""
+            """One decode iteration straight from the pool (paged twin of
+            ``_decode_active``): each layer gathers its lane from the pool
+            inside ``decode_step_paged``. ``poss`` is the host-tracked lane
+            position per active slot; the one KV row per layer the step
+            writes lands at row ``pos % bs`` of block
+            ``table[(pos % W) // bs]`` — a wrap (pos >= W, caching off)
+            overwrites the sequence's own oldest block, which is exactly
+            the contiguous ring semantics. Padding rows replay a real row
+            and null tables write the null block, so duplicate writes carry
+            equal values."""
             sub_tokens = full_tokens[idx]
             keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(idx)
 
             def one(table, token_row, pos, k):
-                with jax.named_scope("lane_gather"):
-                    row = {"k": lane(k_pool, table),
-                           "v": lane(v_pool, table), "pos": pos}
                 with jax.named_scope("model"):
-                    logits, new_row = tfm.decode_step(params, cfg, row,
-                                                      token_row[None])
-                j = (pos % W) // bs
-                dest = table[j]
-                dk = jax.lax.dynamic_slice(new_row["k"], (0, 0, j * bs, 0, 0),
-                                           (L, 1, bs, KH, dh))
-                dv = jax.lax.dynamic_slice(new_row["v"], (0, 0, j * bs, 0, 0),
-                                           (L, 1, bs, KH, dh))
+                    logits, k_rows, v_rows = tfm.decode_step_paged(
+                        params, cfg, k_pool, v_pool, table, pos, token_row)
                 with jax.named_scope("sample"):
-                    nxt = sample(logits[0], k, sampler_cfg)
-                return nxt, dest, dk, dv
+                    nxt = sample(logits, k, sampler_cfg)
+                return nxt, table[(pos % W) // bs], k_rows, v_rows
 
-            nxt, dest, dk, dv = jax.vmap(one)(tables, sub_tokens, poss, keys)
+            nxt, dest, k_rows, v_rows = jax.vmap(one)(tables, sub_tokens, poss,
+                                                      keys)
+            row = poss % bs                       # bs divides W
             with jax.named_scope("pool_write"):
-                k_pool = k_pool.at[dest].set(dk[:, :, 0])
-                v_pool = v_pool.at[dest].set(dv[:, :, 0])
+                k_pool = k_pool.at[dest, :, row].set(k_rows)
+                v_pool = v_pool.at[dest, :, row].set(v_rows)
             return full_tokens.at[idx].set(nxt[:, None]), k_pool, v_pool
 
         self._place_paged = _place_paged

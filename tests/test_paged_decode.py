@@ -4,14 +4,19 @@ every mode combination — while the hit path stops copying KV entirely
 (zero-copy block aliasing) and incremental reservation admits more, recovers
 from grow failures by preemption, and never deadlocks.
 """
+import re
+
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
 from repro.core.scheduler.policies import fcfs
 from repro.core.scheduler.request import Request
 from repro.core.scheduler.scheduler import Scheduler
-from repro.serving.engine import Engine
+from repro.models import transformer as tfm
+from repro.serving.engine import Engine, RealBackend
 from repro.serving.kv_cache import BlockAllocator
 
 
@@ -187,3 +192,133 @@ def test_backend_commits_its_arrays_to_its_device(setup):
     for a in (be.k_pool, be.v_pool, be.slot_tokens,
               *jax.tree.leaves(be.params)):
         assert a.devices() == {dev}
+
+
+# ------------------------------------------- decode straight from the pool
+BS = 16                          # block size of the direct-program tests
+
+
+def _pools(cfg, n_blocks, seed=1):
+    """Random K and V pools (n_blocks, L, BS, KH, dh): rows a lane reads past
+    ``pos`` hold garbage that the mask has to hide."""
+    shape = (n_blocks, cfg.num_layers, BS, cfg.num_kv_heads, cfg.head_dim)
+    kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    return (jax.random.normal(kk, shape, cfg.dtype),
+            jax.random.normal(kv, shape, cfg.dtype))
+
+
+def _lane(pool, table):
+    """A table's blocks as one contiguous (L, 1, W, KH, dh) cache lane."""
+    _, L, bs, kh, dh = pool.shape
+    return jnp.moveaxis(pool[table], 1, 0).reshape(L, 1, len(table) * bs,
+                                                   kh, dh)
+
+
+@pytest.mark.parametrize("pos", [2 * BS + 5, 3 * BS],
+                         ids=["inside_block", "block_boundary"])
+@pytest.mark.parametrize("name", ["llama3_2_3b", "olmoe_1b_7b",
+                                  "qwen2_vl_72b"])
+def test_decode_step_paged_matches_decode_step_on_a_gathered_lane(name, pos):
+    """DENSE, MOE and VLM (mrope): each layer reading its lane from the pool
+    gives the logits and the new K/V rows of ``decode_step`` over the same
+    table gathered into a lane, bit for bit."""
+    cfg = get_smoke_config(name).replace(dtype="float32")
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    k_pool, v_pool = _pools(cfg, 9)
+    table = jnp.asarray([4, 0, 7, 2, 5], jnp.int32)
+    w = len(table) * BS
+    token = jnp.asarray([11], jnp.int32)
+
+    logits, k_rows, v_rows = jax.jit(
+        lambda p, k, v, t, tok: tfm.decode_step_paged(p, cfg, k, v, t, pos,
+                                                      tok))(
+        params, k_pool, v_pool, table, token)
+    cache = {"k": _lane(k_pool, table), "v": _lane(v_pool, table),
+             "pos": jnp.asarray(pos, jnp.int32)}
+    ref_logits, ref_cache = jax.jit(
+        lambda p, c, tok: tfm.decode_step(p, cfg, c, tok))(
+        params, cache, token[None])
+
+    assert k_rows.shape == (cfg.num_layers, cfg.num_kv_heads, cfg.head_dim)
+    assert np.array_equal(logits, ref_logits[0])
+    assert np.array_equal(k_rows, ref_cache["k"][:, 0, pos % w])
+    assert np.array_equal(v_rows, ref_cache["v"][:, 0, pos % w])
+
+
+def _programs(cfg, params, max_batch, cache_len):
+    be = RealBackend(cfg, params, max_batch=max_batch, cache_len=cache_len,
+                     prompt_len=16, paged=True)
+    be._paged_programs(BS)
+    return be
+
+
+def test_decode_writes_one_row_per_layer_and_sequence(setup):
+    """One paged decode step changes the pools only at row ``pos % bs`` of
+    block ``table[(pos % W) // bs]`` of each real row, with the rows
+    ``decode_step_paged`` computes; a padding row replaying a real one and
+    a row on the all-null table leave every other block bit-equal."""
+    cfg, params = setup
+    mb, null = 4, 12
+    w = mb * BS
+    be = _programs(cfg, params, max_batch=4, cache_len=w)
+    k_pool, v_pool = _pools(cfg, null + 1)
+    tables = np.asarray([[3, 7, 1, 9],            # real, pos inside block 1
+                         [0, 5, 10, 2],           # real, pos at a boundary
+                         [3, 7, 1, 9],            # padding: replays row 0
+                         [null] * mb], np.int32)  # unreserved: null table
+    poss = np.asarray([2 * BS + 5, 3 * BS, 2 * BS + 5, BS + 5], np.int32)
+    idx = np.asarray([0, 1, 0, 2], np.int32)
+    slot_tokens = jnp.asarray([[5], [9], [13], [0]], jnp.int32)
+    key = jax.random.PRNGKey(3)
+
+    _, k_new, v_new = be._decode_paged(params, k_pool, v_pool, slot_tokens,
+                                       idx, tables, poss, key)
+
+    changed = np.zeros(k_pool.shape[:1] + k_pool.shape[2:3], bool)
+    for r in (0, 1, 3):
+        changed[tables[r][(poss[r] % w) // BS], poss[r] % BS] = True
+    for old, new in ((k_pool, k_new), (v_pool, v_new)):
+        keep = ~changed[:, None, :, None, None] & np.ones(old.shape, bool)
+        assert np.array_equal(np.asarray(new)[keep], np.asarray(old)[keep])
+    # one row alone: its matmuls round apart from the batch's in the last
+    # bits on the CPU, so the written rows are compared within float32 noise
+    step = jax.jit(lambda t, p, tok: tfm.decode_step_paged(
+        params, cfg, k_pool, v_pool, t, p, tok))
+    for r in (0, 1):
+        _, k_rows, v_rows = step(tables[r], poss[r], slot_tokens[idx[r]])
+        dest, row = tables[r][(poss[r] % w) // BS], poss[r] % BS
+        assert np.allclose(k_new[dest, :, row], k_rows, rtol=1e-5, atol=1e-5)
+        assert np.allclose(v_new[dest, :, row], v_rows, rtol=1e-5, atol=1e-5)
+        assert not np.allclose(k_pool[dest, :, row], k_rows, atol=1e-2)
+
+
+_HLO_ARRAY = re.compile(r"\b(?:pred|[suf](?:8|16|32|64)|bf16)\[([\d,]*)\]")
+
+
+def test_decode_program_builds_no_stacked_lanes():
+    """The compiled decode program holds no array of B·L·W·KH·dh elements:
+    no step gathers every row's whole table for every layer at once, or
+    restacks the layers' lanes. Arrays of the pool's own shape are left
+    out (the step copies the pool it does not own)."""
+    cfg = get_smoke_config("llama3_2_3b").replace(dtype="float32",
+                                                  vocab_size=2048)
+    b, w = 32, 256
+    L, kh, dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    be = _programs(cfg, None, max_batch=b, cache_len=w)
+    pool = (64 + 1, L, BS, kh, dh)
+    spec = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0),
+                                                    cfg))
+    i32 = jnp.int32
+    hlo = be._decode_paged.lower(
+        params, spec(pool, jnp.float32), spec(pool, jnp.float32),
+        spec((b, 1), i32), spec((b,), i32), spec((b, w // BS), i32),
+        spec((b,), i32), jax.random.PRNGKey(0)).compile().as_text()
+
+    stacked = b * L * w * kh * dh
+    shapes = {tuple(int(d) for d in m.split(",") if d)
+              for m in _HLO_ARRAY.findall(hlo)}
+    assert (b, 1, w, kh, dh) in shapes or (b, w, kh, dh) in shapes
+    big = [s for s in shapes
+           if s != pool and int(np.prod(s, dtype=np.int64)) >= stacked]
+    assert not big, f"arrays of >= B*L*W*KH*dh elements: {big}"
