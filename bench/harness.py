@@ -3,8 +3,10 @@
 Everything that belongs to one configuration, traffic mix, cell or metric is
 found by its name in ``BENCHMARK.json``:
 
-* ``bench/configs/<config>.json`` -- the model's sizes as run, the cut, and
-  the serving sizes;
+* ``bench/configs/<config>.json`` -- the model's sizes as run, the cut, the
+  serving sizes, and under ``"arch"`` the module that knows its block
+  (``bench/arch/``: the program's config, the weight layout, the work
+  counts and the plain reference);
 * ``bench/traffic/<mix>.json``    -- parameters of the one generator;
 * ``bench/cells/<cell>.json``     -- the offered rate (from the knee sweep),
   the TTFT and ITL limits, and the limits of the correctness check;
@@ -39,7 +41,8 @@ BENCH = ROOT / "bench"
 #: Serve at most this long past the window for requests due in it.
 DRAIN_S = 60.0
 #: Length of the traced window of a traced run, in seconds: the last of
-#: the measured window. The profiler runs on until the loop has served
+#: the measured window, or where the cell file's ``trace_at_share`` puts it
+#: (see :func:`trace_start`). The profiler runs on until the loop has served
 #: every request, so that writing the trace stalls no request; the
 #: reduction keeps the first ``TRACE_S`` seconds of it.
 TRACE_S = 3.0
@@ -68,6 +71,10 @@ def _applies(metric: dict, cell: str) -> bool:
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` of ``root/BENCHMARK.json``, with its configuration
+    and cell files from under ``root``; the configuration's ``"arch"``
+    becomes an absolute path."""
+    from bench import arch
     bench = json.loads((root / "BENCHMARK.json").read_text())
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
@@ -77,6 +84,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     conf = next(c for c in bench["configs"] if c["name"] == w["config"])
     from bench.traffic import generator
     config = json.loads((root / conf["file"]).read_text())
+    config["arch"] = str(arch.path_of(config, root))
     mix = generator.load_mix(w["traffic"])
     config["serving"].update(mix.get("serving", {}))   # sizes the mix needs
     return Cell(
@@ -95,6 +103,16 @@ def smoke_cell(cell: Cell) -> Cell:
                 cell.end_to_end, cell.per_layer)
 
 
+def trace_start(cell: Cell, seconds: float) -> float:
+    """When a traced run's profiler starts: at the cell file's
+    ``trace_at_share`` of the window, for traffic whose work can end before
+    the window does; else ``TRACE_S`` before the window closes."""
+    share = cell.cell.get("trace_at_share")
+    if share is not None:
+        return share * seconds
+    return max(seconds - TRACE_S, seconds / 3)
+
+
 def read_metric(name: str, run: "RunData"):
     """Load ``bench/metrics/<name>.py`` and return its reading (None where
     it finds nothing to read)."""
@@ -107,28 +125,6 @@ def read_metric(name: str, run: "RunData"):
 
 
 # ---------------------------------------------------------- the program
-def program_config(c: dict):
-    """The program's ``ModelConfig``: its preset, cut as the file says; a
-    width that disagrees with the file is an error, not a silent change."""
-    from repro.configs.base import ACT_SILU, ACT_SQ_RELU, get_config
-    act = {"silu": ACT_SILU, "relu2": ACT_SQ_RELU}[c["hidden_act"]]
-    cfg = get_config(c["preset"]).replace(
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        dtype=c["dtype"])
-    want = dict(activation=act, norm=c["norm"], use_bias=False,
-                rope_theta=float(c["rope_theta"]), pos_emb="rope",
-                tie_embeddings=c["tie_word_embeddings"], sliding_window=None,
-                moe=None)
-    for k, v in want.items():
-        if getattr(cfg, k) != v:
-            raise ValueError(f"preset {c['preset']} has {k}={getattr(cfg, k)}"
-                             f", the configuration file states {v}")
-    return cfg
-
-
 def train_ranker(mix: dict, smoke: bool):
     """The PARS ranker at BERT-base width, trained pairwise from the mix's
     fixed ranker seed (the same ranker for every traffic seed)."""
@@ -203,10 +199,12 @@ class Probe:
     tracing: bool = False       # inside the traced window: work is counted
     traced: bool = False
     trace_flops: float = 0.0
-    decode_calls: List[list] = field(default_factory=list)  # traced contexts
+    #: each traced decode call: (contexts, counts), ``counts`` the change
+    #: of the architecture's ``COUNTERS`` across the call
+    decode_calls: List[tuple] = field(default_factory=list)
     max_batch: int = 1
 
-    def attach(self, engine, shapes, prompt_ids) -> None:
+    def attach(self, engine, shapes, prompt_ids, counters) -> None:
         import jax
         core, be = engine.core, engine.backend
         self.max_batch = engine.scheduler.max_batch
@@ -230,15 +228,20 @@ class Probe:
             return add(reqs)
 
         def decode_w(now):
-            if self.tracing:
+            traced = self.tracing
+            if traced:
                 ctx = [be._pos[r.req_id] + 1 for r in be.slot_req
                        if r is not None and core.decode_ready(r)]
-                self.decode_calls.append(ctx)
-                self.trace_flops += shapes.decode(ctx)["flops"]
+                before = [getattr(be, k) for k in counters]
             t0 = time.perf_counter()
             with ann("bench.decode"):
                 out = decode(now)
             self.decode_s.append(time.perf_counter() - t0)
+            if traced:
+                counts = {k: getattr(be, k) - v
+                          for k, v in zip(counters, before)}
+                self.decode_calls.append((ctx, counts))
+                self.trace_flops += shapes.decode(ctx, counts)["flops"]
             return out
 
         def prefill_w(chunks, now):
@@ -314,7 +317,8 @@ class Probe:
 WARM_IDS = 1 << 40
 
 
-def warm_bursts(sizes, chunk: int, max_batch: int) -> List[list]:
+def warm_bursts(sizes, chunk: int, max_batch: int,
+                cache_len: int) -> List[list]:
     """Bursts of (prompt tokens, output tokens) that, served one after the
     other, reach every dispatch shape a window of this traffic can reach.
     ``sizes`` are the traffic's prompt lengths as the engine pads them.
@@ -324,7 +328,10 @@ def warm_bursts(sizes, chunk: int, max_batch: int) -> List[list]:
     * each size above ``chunk`` once: prefilled a chunk at a time;
     * a staircase of ``max_batch`` requests of the smallest size whose
       outputs end one token apart, once every prompt is in: decode then runs
-      every batch from ``max_batch`` down to 1.
+      every batch from ``max_batch`` down to 1. An output is held to what
+      the cache has room for after the padded prompt; where that cuts it,
+      the prompts, done a chunk budget apart, still reach every batch on
+      the way in and end apart on the way out.
     """
     small = sorted(s for s in sizes if s <= chunk)
     out = [[(s, 2)] * n for s in small
@@ -332,25 +339,57 @@ def warm_bursts(sizes, chunk: int, max_batch: int) -> List[list]:
     out += [[(s, 2)] for s in sorted(sizes) if s > chunk]
     s0 = small[0] if small else min(sizes)
     base = -(-max_batch * s0 // chunk) + 4
-    out.append([(s0, base + i) for i in range(max_batch)])
+    room = cache_len - s0 + 1
+    out.append([(s0, min(base + i, room)) for i in range(max_batch)])
     return out
 
 
-def warm(engine, bursts, scorer, scorer_batches: int) -> None:
-    """Serve ``bursts`` through the engine's own serving loop, each to the
-    end, then drop what they left in the prefix cache; and call the scorer
-    once at every batch size up to ``scorer_batches``."""
+def hit_bursts(sizes, chunk: int, block: int, max_batch: int) -> List[list]:
+    """Bursts of (words of a shared document, words of its own, output
+    tokens) that reach every continuation chunk a prefix-cache hit can make.
+    ``sizes`` are the traffic's prompt lengths as the engine pads them.
+
+    A hit leaves ``r`` positions of a padded prompt to compute: any multiple
+    of ``block`` up to ``chunk`` (or up to the size, less a block). The core
+    packs whole remainders into a step's ``chunk`` budget, so up to
+    ``chunk // r`` of them share one dispatch, padded to a power of two.
+    For each size the document is served first, its whole blocks cached;
+    then, for each ``r`` and padded group, that many requests of the size
+    whose first ``size - r`` tokens are the document's.
+    """
+    out = []
+    for s in sorted(sizes):
+        out.append([(s - 2, 0, 2)])                  # the document: s - 1
+        for r in range(block, min(s - block, chunk) + 1, block):
+            n_max = min(chunk // r, max_batch)
+            groups = sorted({1 << (n - 1).bit_length()
+                             for n in range(1, n_max + 1)})
+            out += [[(s - r - 1, r, 2)] * min(g, n_max) for g in groups]
+    return out
+
+
+def warm(engine, bursts, scorer, scorer_batches: int, hits=()) -> None:
+    """Serve ``bursts`` (of :func:`warm_bursts`), then ``hits`` (of
+    :func:`hit_bursts`, over one document), through the engine's own serving
+    loop, each to the end, then drop what they left in the prefix cache; and
+    call the scorer once at every batch size up to ``scorer_batches``."""
     from bench.traffic.generator import filler, stream
     from repro.core.scheduler.request import Request
     from repro.serving.core import WallClock
     core = engine.core
     rng = stream("warm-up")
+    doc = filler(rng, max(w for b in hits for w, _, _ in b)).split() \
+        if hits else []
     rid = WARM_IDS
-    for burst in bursts:
+    prompts = [[(filler(rng, tokens - 1), out) for tokens, out in burst]
+               for burst in bursts]
+    prompts += [[(" ".join(doc[:shared] + filler(rng, own).split()), out)
+                 for shared, own, out in burst] for burst in hits]
+    for burst in prompts:
         reqs = []
-        for tokens, out in burst:
-            reqs.append(Request(req_id=rid, prompt=filler(rng, tokens - 1),
-                                arrival_time=0.0, prompt_len=tokens,
+        for prompt, out in burst:
+            reqs.append(Request(req_id=rid, prompt=prompt, arrival_time=0.0,
+                                prompt_len=1 + len(prompt.split()),
                                 true_length=out))
             rid += 1
         core.clock = WallClock()
@@ -372,9 +411,11 @@ class RunData:
     end: float              # clock time the loop stopped
     setup_s: float
     probe: Probe
-    shapes: object          # roofline.Shapes
+    shapes: object          # the architecture module's work counts
     peak: dict
     trace: Optional[dict] = None
+    #: every integer counter of the backend: its change over the window
+    counters: Dict[str, int] = field(default_factory=dict)
 
     @property
     def limits(self) -> dict:
@@ -390,7 +431,7 @@ class Session:
         t0 = time.perf_counter()
         import jax
 
-        from bench import roofline, weights
+        from bench import arch, roofline, weights
         from bench.reference import bucket, encode
         from repro.core.predictor.tokenizer import HashTokenizer
         from repro.core.scheduler.policies import make_policy
@@ -403,10 +444,11 @@ class Session:
         self.device = jax.devices()[0]
         self.peak = roofline.PEAKS["TPU v5 lite"] if smoke else \
             roofline.peaks(self.device.device_kind)
-        self.shapes = roofline.Shapes.from_config(c)
+        self.arch = arch.load(c)
+        self.shapes = self.arch.shapes(c)
         self.cache_dir = "off (rehearsal)" if smoke else compile_cache_dir()
         self.compiles = Compiles()
-        cfg = program_config(c)
+        cfg = self.arch.program_config(c)
         # the ranker trains first: its training step needs more of the chip
         # than is left beside the model's weights, and frees it when done
         self.ranker = train_ranker(cell.mix, smoke)
@@ -432,21 +474,29 @@ class Session:
                 record_token_times=True))
         self.ids: Dict[int, list] = {}
         self.probe.attach(self.engine, self.shapes,
-                          lambda r: self.ids[r.req_id])
+                          lambda r: self.ids[r.req_id], self.arch.COUNTERS)
+        self.counters: Dict[str, int] = {}
 
-        sizes = {bucket(len(encode(s.prompt, cfg.vocab_size, pl)), pl)
-                 for s in specs}
+        ids = [encode(s.prompt, cfg.vocab_size, pl) for s in specs]
+        sizes = {bucket(len(i), pl) for i in ids}
         self.bursts = warm_bursts(sizes, sv["prefill_chunk_tokens"],
-                                  sv["max_batch"])
+                                  sv["max_batch"], sv["cache_len"])
+        # prompts that share a first block can hit the prefix cache
+        heads = {tuple(i[:sv["block_size"]]) for i in ids}
+        self.hits = (hit_bursts(sizes, sv["prefill_chunk_tokens"],
+                                sv["block_size"], sv["max_batch"])
+                     if sv["prefix_caching"] and len(heads) < len(ids)
+                     else [])
         n0 = self.compiles.n
         warm(self.engine, self.bursts, self.ranker.score,
-             cell.cell["scorer_batches"])
+             cell.cell["scorer_batches"], self.hits)
         self.probe.reset()
         self.setup_s = time.perf_counter() - t0
         log(f"[{cell.name} seed={seed}] set-up {self.setup_s:.2f} s (weights "
             f"{t_weights:.2f} s, ranker {t_ranker:.2f} s, warm-up "
             f"{self.compiles.n - n0} programs compiled or loaded, "
-            f"{len(self.bursts)} bursts over prompt sizes {sorted(sizes)}); "
+            f"{len(self.bursts)} bursts over prompt sizes {sorted(sizes)}, "
+            f"{len(self.hits)} over prefix hits); "
             f"compile cache {self.cache_dir}")
 
     def serve(self, specs, seconds: float, *, id_base: int = 0):
@@ -464,6 +514,7 @@ class Session:
         for r in reqs:
             self.ids[r.req_id] = encode(r.prompt, vocab, self.prompt_len)
         core, probe, be = self.engine.core, self.probe, self.engine.backend
+        c0 = _int_counters(be)
         n0, d0 = self.compiles.n, (be.prefill_dispatches,
                                    be.extend_dispatches, be.prefix_installs,
                                    core.rerank_count)
@@ -472,6 +523,7 @@ class Session:
         core.run(max_time=seconds + DRAIN_S, on_step=probe.on_step)
         end = core.clock.now()
         probe.stop()
+        self.counters = {k: v - c0[k] for k, v in _int_counters(be).items()}
         late = np.asarray([probe.delivered[r.req_id] - r.arrival_time
                            for r in reqs if r.req_id in probe.delivered])
         done = sum(r.finish_time is not None for r in reqs)
@@ -515,6 +567,13 @@ class Session:
         gc.collect()
 
 
+def _int_counters(backend) -> Dict[str, int]:
+    """The backend's public integer attributes: its counters (and sizes,
+    which do not change)."""
+    return {k: v for k, v in vars(backend).items()
+            if type(v) is int and not k.startswith("_")}
+
+
 def _sample(finished, seed: int) -> list:
     """The longest finished request, then others drawn from the seed, until
     the sample holds ``SAMPLE_TOKENS`` served tokens."""
@@ -538,9 +597,11 @@ def check_served(c: dict, seed: int, sample, prompt_len: int,
     """Widest gap by which a served token's logit lies below the reference's
     best, over the sample; with ``quant`` the control's reading instead: the
     gap of the token the float8 pass puts first, at the same positions."""
-    from bench.reference import Reference, gaps, served_sequence
-    ref = Reference(c, seed)
-    ctl = Reference(c, seed, quant=True) if quant else None
+    from bench import arch
+    from bench.reference import gaps, served_sequence
+    reference = arch.load(c).Reference
+    ref = reference(c, seed)
+    ctl = reference(c, seed, quant=True) if quant else None
     worst, n = 0.0, 0
     for prompt, served in sample:
         seq, first = served_sequence(prompt, served, c["vocab_size"],
@@ -554,12 +615,14 @@ def check_served(c: dict, seed: int, sample, prompt_len: int,
 
 
 def run(name: str, seed: int, seconds: float, trace: bool, *,
-        smoke: bool = False, work_dir: Optional[Path] = None) -> dict:
-    """One run of cell ``name``; returns the result line's object. With
-    ``smoke`` the cell runs at its CPU rehearsal size on whatever device JAX
-    has (the caller decides whether that device may report)."""
+        smoke: bool = False, work_dir: Optional[Path] = None,
+        root: Path = ROOT) -> dict:
+    """One run of cell ``name`` of ``root/BENCHMARK.json``; returns the
+    result line's object. With ``smoke`` the cell runs at its CPU rehearsal
+    size on whatever device JAX has (the caller decides whether that device
+    may report)."""
     from bench.traffic import generator
-    cell = load_cell(name)
+    cell = load_cell(name, root)
     if smoke:
         cell = smoke_cell(cell)
     specs = generator.trace(cell.mix, cell.cell["rate_per_s"], seconds, seed)
@@ -571,7 +634,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     shutil.rmtree(trace_dir, ignore_errors=True)
     sess = Session(cell, seed, specs, smoke=smoke,
                    trace_dir=trace_dir if trace else None,
-                   trace_at=max(seconds - TRACE_S, seconds / 3))
+                   trace_at=trace_start(cell, seconds))
     reqs, end = sess.serve(specs, seconds)
     stats = sess.device.memory_stats() or {}
     probe = sess.probe
@@ -589,7 +652,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
             f"{trace_red.get('decode_bound', 'no decode program found')}")
     data = RunData(cell=cell, seconds=seconds, requests=reqs, end=end,
                    setup_s=sess.setup_s, probe=probe, shapes=sess.shapes,
-                   peak=sess.peak, trace=trace_red)
+                   peak=sess.peak, trace=trace_red, counters=sess.counters)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = read_metric(m["name"], data)

@@ -1,7 +1,8 @@
-"""Compile a configuration's serving programs at its cell sizes for a
-described TPU v5e and print what each needs (``memory_analysis()``).
+"""Compile a cell's serving programs at its sizes (the configuration's, with
+the traffic mix's) for a described TPU v5e and print what each needs
+(``memory_analysis()``).
 
-    JAX_PLATFORMS=cpu python bench/rehearse_memory.py nemo4-15b cmdr-32b
+    JAX_PLATFORMS=cpu python bench/rehearse_memory.py cmdr-32b.doc_sessions
 
 Nothing runs: the TPU compiler, installed beside the CPU backend, compiles
 for a chip that is not attached. Each program's need is its arguments plus
@@ -29,8 +30,8 @@ def main(names) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import roofline
-    from bench.harness import program_config
+    from bench import arch, weights
+    from bench.harness import load_cell
     from repro.serving.engine import RealBackend
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -42,10 +43,9 @@ def main(names) -> None:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=shard)
 
     for name in names:
-        c = json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
-        sv, cfg = c["serving"], program_config(c)
-        shapes = roofline.Shapes.from_config(c)
-        from bench import weights
+        c = load_cell(name).config
+        model = arch.load(c)
+        sv, cfg, shapes = c["serving"], model.program_config(c), model.shapes(c)
         params = jax.tree.map(lambda a: s(a.shape, a.dtype),
                               jax.eval_shape(lambda: weights.make(c, 0)))
         be = RealBackend(cfg, None, max_batch=sv["max_batch"],
@@ -53,22 +53,24 @@ def main(names) -> None:
                          paged=True)
         bs, w = sv["block_size"], sv["cache_len"]
         be._paged_programs(bs)
-        L, kh, dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        pool = s((sv["kv_blocks"] + 1, L, bs, kh, dh))
+        L, per_token = shapes.kv_row[0], shapes.kv_row[1:]
+        pool = s((sv["kv_blocks"] + 1, L, bs) + per_token, c["dtype"])
         i32, key = jnp.int32, s((2,), jnp.uint32)
         tok = s((sv["max_batch"], 1), i32)
         chunk = sv["prefill_chunk_tokens"]
         # the widest dispatch of each kind: a step's chunk budget filled by
-        # the smallest bucket, one chunk of a long prompt, decode at max_batch
+        # the smallest bucket, one chunk of a long prompt, the budget filled
+        # by one-block remainders of prefix hits, decode at max_batch
         grid = {"prefill": {64: [min(chunk // 64, sv["max_batch"])],
                             chunk: [1]},
-                "extend": {chunk: [1]}, "decode": [sv["max_batch"]]}
+                "extend": {chunk: [1], bs: [min(chunk // bs, sv["max_batch"])]},
+                "decode": [sv["max_batch"]]}
         cases = []
         for ln, bsz in grid["prefill"].items():
             b = max(bsz)
             cases.append((f"prefill_bucket L={ln} B={b}", be._prefill_bucket,
                           (params, s((b, ln), i32), s((b,), i32), key)))
-            lane = s((L, b, w, kh, dh))
+            lane = s((L, b, w) + per_token, c["dtype"])
             cases.append((f"place_paged L={ln} B={b}", be._place_paged,
                           (pool, pool, lane, lane, tok, s((b,), i32),
                            s((b, -(-ln // bs)), i32), s((b,), i32))))
@@ -84,7 +86,7 @@ def main(names) -> None:
                       (params, pool, pool, tok, s((b,), i32),
                        s((b, w // bs), i32), s((b,), i32), key)))
         wb = shapes.weight_bytes()
-        pool_b = 2 * (sv["kv_blocks"] + 1) * bs * shapes.kv_bytes_per_token // 2
+        pool_b = (sv["kv_blocks"] + 1) * bs * shapes.kv_bytes_per_token
         print(f"{name}: weights {wb['layers'] / GB:.3f} GB layers + "
               f"{wb['embedding_and_head'] / GB:.3f} GB embedding/head = "
               f"{wb['total'] / GB:.3f} GB; KV {shapes.kv_bytes_per_token} B/token;"
@@ -102,4 +104,5 @@ def main(names) -> None:
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or ["nemo4-15b", "cmdr-32b"])
+    main(sys.argv[1:] or [w["name"] for w in json.loads(
+        (ROOT / "BENCHMARK.json").read_text())["workloads"]])

@@ -1,9 +1,10 @@
 """Find a cell's knee: the highest offered rate that meets the cell's TTFT
 limit without a growing queue. One process builds and warms the cell once,
 then offers windows at rising rates (each a fresh draw of the cell's mix at
-that rate).
+that rate), one for each seed.
 
-    python bench/sweep.py --workload <cell> --rates 6,9,12 --seconds 20
+    python bench/sweep.py --workload <cell> --rates 6,9,12 --seconds 20 \
+        --seeds 1,2,3
 
 For each rate it prints the requests offered and finished, the backlog
 (requests in the system) and the waiting queue (requests not yet admitted)
@@ -13,8 +14,10 @@ window, and the TTFT tail. A rate is sustained when the TTFT tail meets the
 cell's limit and the waiting queue at the end of the window is no longer
 than in its middle third (by two requests). The backlog alone cannot say
 so: requests that are running, such as a long answer still decoding at the
-close, are in it at any rate. It stops after the first rate that is not
-sustained. Run it once on the chip; the cell file records the result.
+close, are in it at any rate. A rate is sustained only where every seed
+sustains it: the seed orders the arrivals, and a tail depends on the order.
+It stops after the first rate that is not sustained. Run it once on the
+chip; the cell file records the result.
 """
 from __future__ import annotations
 
@@ -34,7 +37,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--rates", required=True)
     ap.add_argument("--seconds", type=float, default=20.0)
-    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--seeds", default="1")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
 
@@ -45,19 +48,21 @@ def main(argv=None) -> int:
     if args.smoke:
         cell = harness.smoke_cell(cell)
     rates = [float(r) for r in args.rates.split(",")]
-    traces = [generator.trace(cell.mix, r, args.seconds, args.seed)
-              for r in rates]
+    seeds = [int(s) for s in args.seeds.split(",")]
+    traces = {(r, sd): generator.trace(cell.mix, r, args.seconds, sd)
+              for r in rates for sd in seeds}
     if args.smoke:
-        for t in traces:
+        for t in traces.values():
             for s in t:
                 s.true_length = min(s.true_length,
                                     cell.config["serving"]["max_output_tokens"])
-    sess = harness.Session(cell, args.seed, [s for t in traces for s in t],
+    sess = harness.Session(cell, seeds[0],
+                           [s for t in traces.values() for s in t],
                            smoke=args.smoke)
     rows = []
     base = 0
     step = sess.probe.on_step
-    for rate, specs in zip(rates, traces):
+    for (rate, seed), specs in traces.items():
         backlog = []
 
         def on_step(core, now, backlog=backlog):
@@ -80,7 +85,7 @@ def main(argv=None) -> int:
         run = harness.RunData(cell=cell, seconds=t, requests=reqs, end=end,
                               setup_s=0.0, probe=sess.probe,
                               shapes=sess.shapes, peak=sess.peak)
-        row = {"rate_per_s": rate, "offered": len(reqs),
+        row = {"rate_per_s": rate, "seed": seed, "offered": len(reqs),
                "finished": len(done),
                "backlog_mid": float(mid.mean()) if len(mid) else 0.0,
                "backlog_end": float(last.mean()) if len(last) else 0.0,
@@ -96,11 +101,15 @@ def main(argv=None) -> int:
         rows.append(row)
         print(json.dumps(row), flush=True)
         sess.engine.core.finished.clear()
-        if not row["sustained"]:
+        if seed == seeds[-1] and not all(
+                r["sustained"] for r in rows if r["rate_per_s"] == rate):
             break
-    knee = max((r["rate_per_s"] for r in rows if r["sustained"]), default=None)
+    held = {r["rate_per_s"] for r in rows} - {
+        r["rate_per_s"] for r in rows if not r["sustained"]}
+    knee = max(held, default=None)
     print(json.dumps({"workload": args.workload, "knee_per_s": knee,
-                      "seconds": args.seconds, "sweep": rows}))
+                      "seconds": args.seconds, "seeds": seeds,
+                      "sweep": rows}))
     return 0
 
 

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from bench import harness, weights
-from bench.reference import Reference, served_sequence
+from bench.arch.dense_gqa import Reference, _embed
+from bench.reference import served_sequence
 from bench.traffic import generator
 from repro.serving.engine import RealBackend
 
@@ -36,7 +37,6 @@ def test_reference_draws_the_programs_weights():
     seq, first = served_sequence("explain topic3 w1 w2", [5, 6, 7],
                                  c["vocab_size"], 128)
     emb = np.asarray(w["embed"], np.float32)[seq]
-    from bench.reference import _embed
     got = np.asarray(_embed(jnp.asarray(seq), ref.salts, dm=ref.dm,
                             lay=ref.lay))
     assert np.array_equal(emb, got)
@@ -63,10 +63,14 @@ def _broken_decode(monkeypatch, fault):
         decode, vocab = self._decode_paged, self.cfg.vocab_size
 
         def broken(params, k_pool, v_pool, *args):
-            tokens, k_new, v_new = decode(params, k_pool, v_pool, *args)
             if fault == "token":           # a token altered where produced
+                tokens, k_new, v_new = decode(params, k_pool, v_pool, *args)
                 return (tokens + 1) % vocab, k_new, v_new
-            return tokens, k_pool, v_pool  # the step leaves its state as it was
+            # the step leaves its state as it was: the pools are copied
+            # first, since a program that donates them deletes its inputs
+            k_old, v_old = jnp.copy(k_pool), jnp.copy(v_pool)
+            tokens, _, _ = decode(params, k_pool, v_pool, *args)
+            return tokens, k_old, v_old
         self._decode_paged = broken
     monkeypatch.setattr(RealBackend, "_paged_programs", programs)
 

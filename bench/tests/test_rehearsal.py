@@ -46,7 +46,7 @@ def test_command_refuses_a_cpu_and_prints_no_result():
 
 
 def test_warm_bursts_reach_every_group_and_decode_batch():
-    bursts = harness.warm_bursts({64, 128, 256, 1024}, 256, 32)
+    bursts = harness.warm_bursts({64, 128, 256, 1024}, 256, 32, 2048)
     groups = [(b[0][0], len(b)) for b in bursts[:-1]]
     assert groups == [(64, 1), (64, 2), (64, 3), (64, 4), (128, 1), (128, 2),
                       (256, 1), (1024, 1)]
@@ -55,3 +55,23 @@ def test_warm_bursts_reach_every_group_and_decode_batch():
     outs = sorted(o for _, o in stair)
     assert outs == list(range(outs[0], outs[0] + 32))
     assert outs[0] > 32 * 64 // 256    # every prompt is in before one ends
+
+
+def test_warm_staircase_stays_inside_the_cache():
+    # doc_sessions: every prompt pads to 2048 in a 2176-token cache
+    stair = harness.warm_bursts({2048}, 256, 16, 2176)[-1]
+    assert len(stair) == 16 and {s for s, _ in stair} == {2048}
+    assert max(o for _, o in stair) == 2176 - 2048 + 1
+
+
+def test_hit_bursts_reach_every_continuation_shape():
+    doc, *hits = harness.hit_bursts({2048}, 256, 16, 16)
+    assert doc == [(2046, 0, 2)]
+    assert all(len(set(b)) == 1 and b[0][0] + b[0][1] + 1 == 2048
+               for b in hits)
+    # (remainder, lanes padded to a power of two) of every dispatch that a
+    # step's 256-token budget can pack from prefix-hit remainders
+    pow2 = lambda n: 1 << (n - 1).bit_length()
+    want = {(r, pow2(n)) for r in range(16, 257, 16)
+            for n in range(1, min(256 // r, 16) + 1)}
+    assert {(b[0][1], pow2(len(b))) for b in hits} == want

@@ -5,13 +5,14 @@ from pathlib import Path
 import pytest
 
 from bench import roofline
+from bench.arch import dense_gqa
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 GB = 1e9
 
 
 def shapes(name):
-    return roofline.Shapes.from_config(
+    return dense_gqa.shapes(
         json.loads((CONFIGS / f"{name}.json").read_text()))
 
 

@@ -62,3 +62,14 @@ def test_a_trace_recorded_on_the_cpu(tmp_path):
     assert 0 < red["busy_s"] < red["window_s"]
     assert 0 < red["idle_share"] < 100
     assert any(n == "bench.scorer" for n, _ in red["breakdown"]["idle_gaps"])
+
+
+@pytest.mark.parametrize("cell,seconds,start", [
+    ("nemo4-15b.pars_mixed", 36.0, 33.0),    # the window's last 3 s
+    ("nemo4-15b.pars_mixed", 6.0, 3.0),
+    ("cmdr-32b.doc_sessions", 36.0, 16.2),   # the cell file's share
+])
+def test_trace_start(cell, seconds, start):
+    from bench import harness
+    assert harness.trace_start(harness.load_cell(cell), seconds) == \
+        pytest.approx(start)

@@ -160,12 +160,13 @@ def module_seconds(modules: Dict[str, float], part: str) -> float:
 
 
 def reduce_dir(trace_dir: Path, *, shapes, peak: dict,
-               decode_calls: Sequence[Sequence[int]],
+               decode_calls: Sequence[Tuple[Sequence[int], dict]],
                flops: float, clip_s: Optional[float] = None) -> Optional[dict]:
     """Reduce the one trace under ``trace_dir`` (its first ``clip_s``
     seconds, where given) and add the model-step shares: the decode program
     against its roofline, and the traced work's FLOPs against the chip's
-    peak over the window."""
+    peak over the window. ``decode_calls`` holds each traced decode call's
+    (contexts, counts), which the architecture's ``shapes.decode`` takes."""
     from bench.roofline import roofline_seconds
     files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
     if not files:
@@ -174,8 +175,8 @@ def reduce_dir(trace_dir: Path, *, shapes, peak: dict,
     if red is None:
         return None
     dev_decode = module_seconds(red["modules"], "decode_paged")
-    need = [roofline_seconds(**_fb(shapes.decode(c)), peak=peak)
-            for c in decode_calls if c]
+    need = [roofline_seconds(**_fb(shapes.decode(c, n)), peak=peak)
+            for c, n in decode_calls if c]
     if dev_decode > 0 and need:
         red["decode_roofline"] = 100.0 * sum(t for t, _ in need) / dev_decode
         bounds = {b for _, b in need}

@@ -7,10 +7,11 @@ weights again without taking anything the program made. The salts depend on
 the seed and the leaf's name only; the seed enters as data, so a new seed
 compiles nothing.
 
-The tree has the layout the serving program consumes (stacked layers). Each
-matrix is uniform with the standard deviation the program's own initializer
-gives it (1/sqrt(fan-in); 0.02 for the embedding); LayerNorms start at scale
-1 and bias 0, as the program's initializer has them.
+The tree has the layout the serving program consumes, as the configuration's
+architecture module gives it (``bench.arch``). Each matrix is uniform with
+the standard deviation the program's own initializer gives it
+(1/sqrt(fan-in); 0.02 for the embedding); norms start at scale 1 and bias 0,
+as the program's initializer has them.
 """
 from __future__ import annotations
 
@@ -23,28 +24,12 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def layout(c: dict) -> dict:
-    """{leaf path: (shape, std or 'one'/'zero')} for a configuration file."""
-    d, L, dh = c["hidden_size"], c["num_hidden_layers"], c["head_dim"]
-    h, kh, f, v = (c["num_attention_heads"], c["num_key_value_heads"],
-                   c["intermediate_size"], c["vocab_size"])
-    out = {
-        "embed": ((v, d), 0.02),
-        "final_norm/scale": ((d,), "one"), "final_norm/bias": ((d,), "zero"),
-        "layers/ln1/scale": ((L, d), "one"), "layers/ln1/bias": ((L, d), "zero"),
-        "layers/ln2/scale": ((L, d), "one"), "layers/ln2/bias": ((L, d), "zero"),
-        "layers/attn/wq": ((L, d, h * dh), d ** -0.5),
-        "layers/attn/wk": ((L, d, kh * dh), d ** -0.5),
-        "layers/attn/wv": ((L, d, kh * dh), d ** -0.5),
-        "layers/attn/wo": ((L, h * dh, d), (h * dh) ** -0.5),
-        "layers/mlp/w_up": ((L, d, f), d ** -0.5),
-        "layers/mlp/w_down": ((L, f, d), f ** -0.5),
-    }
-    if c["hidden_act"] == "silu":
-        out["layers/mlp/w_gate"] = ((L, d, f), d ** -0.5)
-    if not c["tie_word_embeddings"]:
-        out["lm_head"] = ((d, v), d ** -0.5)
-    return out
+def layout_of(c: dict) -> dict:
+    """{leaf path: (shape, std or 'one'/'zero')}: the layout of the module
+    that the configuration names (``bench.arch``). ``make`` and every
+    reference draw from it."""
+    from bench import arch
+    return arch.load(c).layout(c)
 
 
 def salts(names, seed: int) -> np.ndarray:
@@ -134,6 +119,6 @@ def _program(spec: tuple, dtype: str):
 
 def make(c: dict, seed: int):
     """The configuration's weights for ``seed``, on the default device."""
-    spec = tuple((n, (tuple(s), i)) for n, (s, i) in layout(c).items())
+    spec = tuple((n, (tuple(s), i)) for n, (s, i) in layout_of(c).items())
     names, program = _program(spec, c["dtype"])
     return program(jnp.asarray(salts(names, seed)))
